@@ -104,7 +104,6 @@ class UniformQuantCodec(Codec):
     bits: int = 8
     stochastic: bool = True
     scale_bits: int = 32             # one fp32 scale per tensor
-    interpret: bool = True           # Pallas interpret-mode fallback
 
     def __post_init__(self):
         super().__post_init__()
@@ -145,8 +144,7 @@ class UniformQuantCodec(Codec):
     def apply(self, key, x):
         from repro.kernels.quantize.ops import quantize_dequantize
         return quantize_dequantize(x, key, bits=self.bits,
-                                   stochastic=self.stochastic,
-                                   interpret=self.interpret)
+                                   stochastic=self.stochastic)
 
 
 @dataclass(frozen=True)
@@ -248,8 +246,7 @@ CODEC_NAMES = ("fp32", "int8", "int4", "topk", "fp8")
 
 
 def get_codec(name: str, *, bits: int | None = None, topk_frac: float = 0.05,
-              omega: int | None = None, stochastic: bool = True,
-              interpret: bool = True) -> Codec:
+              omega: int | None = None, stochastic: bool = True) -> Codec:
     """Codec presets by name (``bits`` overrides the int quantizer width).
 
     ``omega`` only pins the identity codec's width; left None, the identity
@@ -259,7 +256,7 @@ def get_codec(name: str, *, bits: int | None = None, topk_frac: float = 0.05,
             bits_per_element=None if omega is None else omega + 1)
     if name in ("int8", "int4"):
         return UniformQuantCodec(bits=bits or int(name[3:]),
-                                 stochastic=stochastic, interpret=interpret)
+                                 stochastic=stochastic)
     if name == "topk":
         return TopKCodec(frac=topk_frac)
     if name == "fp8":

@@ -45,20 +45,6 @@ from repro.sharding.rules import data_axes, params_specs
 
 
 # --------------------------------------------------------------- common ----
-def _shard_map(f, mesh: Mesh, in_specs, out_specs, manual):
-    """shard_map across jax versions: >= 0.5 exposes ``jax.shard_map`` with
-    ``axis_names``/``check_vma``; 0.4.x has the experimental API with the
-    complementary ``auto`` set and ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    auto = frozenset(mesh.axis_names) - frozenset(manual)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False, auto=auto)
-
-
 def _client_axes(mesh: Mesh):
     ca = data_axes(mesh)
     return ca if len(ca) > 1 else ca[0]
@@ -186,11 +172,11 @@ def make_phsfl_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
     nargs = 6 if participation else 5
     body = per_client if participation else (
         lambda pr, st, b, au, ab: per_client(pr, st, b, au, ab, None))
-    shd = _shard_map(
-        body, mesh,
+    shd = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(lead,) * nargs,
         out_specs=(lead, lead, P()),
-        manual=manual)
+        axis_names=manual, check_vma=False)
 
     if participation:
         def round_fn(params, opt_state, batch, alpha_u, alpha_b, mask):

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -2.0 ** 30
@@ -99,11 +101,11 @@ def flash_attention_hmajor(q, k, v, *, causal: bool = True, window: int = 0,
                            softcap: float = 0.0,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (B,H,S,d); k,v: (B,KVH,S,d).  Returns (B,H,S,d).
 
-    interpret=True executes the kernel body on CPU (this container); on TPU
-    pass interpret=False.
+    ``interpret=None`` compiles the kernel on a TPU and interprets it
+    elsewhere (:func:`repro.kernels.interpret_mode`).
     """
     b, h, s, d = q.shape
     kvh = k.shape[1]
@@ -135,5 +137,5 @@ def flash_attention_hmajor(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

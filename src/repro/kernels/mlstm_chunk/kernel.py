@@ -8,6 +8,12 @@ while cross-chunk state keeps total work linear in sequence length.
 
 Matches repro.models.xlstm.mlstm_chunkwise (the jnp implementation used by
 the model) and the recurrent decode step (tested).
+
+Every value in the body is 2-D so it maps onto (8, 128) vreg tiles: the
+gates arrive as (chunk, 1) columns (index t on sublanes), and the
+within-chunk cumulative sum and running max that Mosaic has no primitive
+for are masked lane reductions over the (t, s) plane, whose row-broadcast
+form [t, s] = x_s is the transpose of the column's lane broadcast.
 """
 
 from __future__ import annotations
@@ -18,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 DEFAULT_CHUNK = 128
 NEG_BIG = -1e30
@@ -36,46 +44,54 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (bt, dh)
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
-    li = li_ref[0, 0].astype(jnp.float32)        # (bt,)
+    li = li_ref[0, 0].astype(jnp.float32)        # (bt, 1)
     lf = lf_ref[0, 0].astype(jnp.float32)
 
-    m_prev = m_ref[0]
-    a = jnp.cumsum(lf)                           # (bt,)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = t_idx >= s_idx
+
+    def by_s(col):                               # [t, s] = col_s
+        return jnp.broadcast_to(col, (chunk, chunk)).T
+
+    m_prev = m_ref[...]                          # (1, 1)
+    a = jnp.sum(jnp.where(causal, by_s(lf), 0.0), axis=1,
+                keepdims=True)                   # cumsum(lf), (bt, 1)
     g = li - a
-    run_max = jax.lax.cummax(g, axis=0)
-    M = jnp.maximum(m_prev, run_max)             # (bt,)
+    g_s = by_s(g)
+    run_max = jnp.max(jnp.where(causal, g_s, NEG_BIG), axis=1,
+                      keepdims=True)             # cummax(g), (bt, 1)
+    M = jnp.maximum(m_prev, run_max)
     m_t = a + M
 
     # intra-chunk decay matrix D[t,s] = exp(g_s - M_t), s <= t
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    D = jnp.where(t_idx >= s_idx, jnp.exp(g[None, :] - M[:, None]), 0.0)
+    D = jnp.where(causal, jnp.exp(g_s - M), 0.0)
 
     scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * D  # (bt,bt)
     h_intra = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())))
     n_intra = jax.lax.dot_general(D, k, (((1,), (0,)), ((), ())))     # (bt,dh)
 
-    decay = jnp.exp(m_prev - M)                  # (bt,)
+    decay = jnp.exp(m_prev - M)                  # (bt, 1)
     h_inter = jax.lax.dot_general(q, c_ref[...], (((1,), (0,)), ((), ()))) \
-        * decay[:, None]
-    n_tot = n_intra + n_ref[...][None, :] * decay[:, None]
-    denom = jnp.maximum(jnp.abs(jnp.sum(q * n_tot, axis=1)), jnp.exp(-m_t))
-    o_ref[0, 0] = ((h_intra + h_inter) / denom[:, None]).astype(o_ref.dtype)
+        * decay
+    n_tot = n_intra + n_ref[...] * decay
+    denom = jnp.maximum(jnp.abs(jnp.sum(q * n_tot, axis=1, keepdims=True)),
+                        jnp.exp(-m_t))
+    o_ref[0, 0] = ((h_intra + h_inter) / denom).astype(o_ref.dtype)
 
     # ---- carry update ----
-    M_L = M[chunk - 1]
-    m_new = m_t[chunk - 1]
-    w_s = jnp.exp(g - M_L)                       # (bt,)
+    M_L = M[chunk - 1:, :]                       # (1, 1)
+    w_s = jnp.exp(g - M_L)                       # (bt, 1)
     c_ref[...] = c_ref[...] * jnp.exp(m_prev - M_L) + \
-        jax.lax.dot_general(k * w_s[:, None], v, (((0,), (0,)), ((), ())))
+        jax.lax.dot_general(k * w_s, v, (((0,), (0,)), ((), ())))
     n_ref[...] = n_ref[...] * jnp.exp(m_prev - M_L) + \
-        jnp.sum(k * w_s[:, None], axis=0)
-    m_ref[0] = m_new
+        jnp.sum(k * w_s, axis=0, keepdims=True)
+    m_ref[...] = m_t[chunk - 1:, :]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunk_pallas(q, k, v, li, lf, *, chunk: int = DEFAULT_CHUNK,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """q,k,v: (B,H,S,dh); li,lf: (B,H,S) log input/forget gates.
 
     Returns h: (B,H,S,dh).
@@ -87,7 +103,6 @@ def mlstm_chunk_pallas(q, k, v, li, lf, *, chunk: int = DEFAULT_CHUNK,
 
     kernel = functools.partial(_mlstm_kernel, chunk=chunk)
     blk4 = lambda b_, h_, ci: (b_, h_, ci, 0)
-    blk3 = lambda b_, h_, ci: (b_, h_, ci)
     return pl.pallas_call(
         kernel,
         grid=(b, h, nc),
@@ -95,15 +110,15 @@ def mlstm_chunk_pallas(q, k, v, li, lf, *, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, 1, chunk, dh), blk4),
             pl.BlockSpec((1, 1, chunk, dh), blk4),
             pl.BlockSpec((1, 1, chunk, dh), blk4),
-            pl.BlockSpec((1, 1, chunk), blk3),
-            pl.BlockSpec((1, 1, chunk), blk3),
+            pl.BlockSpec((1, 1, chunk, 1), blk4),
+            pl.BlockSpec((1, 1, chunk, 1), blk4),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, dh), blk4),
         out_shape=jax.ShapeDtypeStruct((b, h, s, dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((dh, dh), jnp.float32),
-            pltpu.VMEM((dh,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, dh), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(q, k, v, li, lf)
+        interpret=interpret_mode(interpret),
+    )(q, k, v, li[..., None], lf[..., None])

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 LANES = 128
 DEFAULT_BLOCK_M = 256
 
@@ -39,7 +41,7 @@ def _qdq_kernel(x_ref, u_ref, scale_ref, o_ref, *, qmax: int):
                    static_argnames=("qmax", "block_m", "interpret"))
 def quantize_dequantize_pallas(x, u, scale, *, qmax: int,
                                block_m: int = DEFAULT_BLOCK_M,
-                               interpret: bool = True):
+                               interpret: bool | None = None):
     """x, u: (M, 128) with M % block_m == 0; scale: (1, 1) float32."""
     m, lanes = x.shape
     assert lanes == LANES and u.shape == x.shape, (x.shape, u.shape)
@@ -57,5 +59,5 @@ def quantize_dequantize_pallas(x, u, scale, *, qmax: int,
         ],
         out_specs=pl.BlockSpec((block_m, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, LANES), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, u, scale)

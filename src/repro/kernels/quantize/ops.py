@@ -26,18 +26,17 @@ def tensor_scale(x, qmax: int):
     return (jnp.max(jnp.abs(x.astype(jnp.float32))) / qmax).reshape(1, 1)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _qdq_ste(x, u, scale, qmax, interpret):
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _qdq_ste(x, u, scale, qmax):
     """Padded (M, 128) quantize-dequantize with straight-through gradient."""
-    return quantize_dequantize_pallas(x, u, scale, qmax=qmax,
-                                      interpret=interpret)
+    return quantize_dequantize_pallas(x, u, scale, qmax=qmax)
 
 
-def _qdq_fwd(x, u, scale, qmax, interpret):
-    return _qdq_ste(x, u, scale, qmax, interpret), (u.shape,)
+def _qdq_fwd(x, u, scale, qmax):
+    return _qdq_ste(x, u, scale, qmax), (u.shape,)
 
 
-def _qdq_bwd(qmax, interpret, res, g):
+def _qdq_bwd(qmax, res, g):
     (u_shape,) = res
     return g, jnp.zeros(u_shape, g.dtype), jnp.zeros((1, 1), jnp.float32)
 
@@ -46,7 +45,7 @@ _qdq_ste.defvjp(_qdq_fwd, _qdq_bwd)
 
 
 def quantize_dequantize(x, key, *, bits: int = 8, stochastic: bool = True,
-                        interpret: bool = True, use_ref: bool = False):
+                        use_ref: bool = False):
     """Fake-quantize ``x`` to ``bits``-bit symmetric integers, any shape.
 
     ``key`` drives the stochastic rounding (ignored when
@@ -73,7 +72,7 @@ def quantize_dequantize(x, key, *, bits: int = 8, stochastic: bool = True,
         pad = (-n) % tile
         xp = jnp.pad(flat, (0, pad)).reshape(-1, LANES)
         up = jnp.pad(u_flat, (0, pad)).reshape(-1, LANES)
-        out = _qdq_ste(xp, up, scale, qmax, interpret)
+        out = _qdq_ste(xp, up, scale, qmax)
         out = out.reshape(-1)[:n].reshape(x.shape)
     if probe is not None:
         # scale + round + clip + dequant per element

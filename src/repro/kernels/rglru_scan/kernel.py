@@ -9,6 +9,10 @@ the streaming minimum (the roofline memory term).
 
 The diagonal recurrence is elementwise over width, so the width tile (lanes)
 can be large (512) while the time tile bounds the sequential inner loop.
+The tile's decay exp(log_a) and input are first widened to f32 VMEM
+scratch in whole-tile passes; the loop then steps one f32 row at a time
+through ``pl.ds`` ref windows (a single packed bf16 row is not addressable
+at an arbitrary offset) and the finished tile is narrowed back in one store.
 """
 
 from __future__ import annotations
@@ -20,31 +24,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_W = 512
 
 
-def _rglru_kernel(log_a_ref, b_ref, h0_ref, o_ref, carry_ref, *, block_t: int):
+def _rglru_kernel(log_a_ref, b_ref, h0_ref, o_ref, carry_ref, a_ref, x_ref,
+                  h_ref, *, block_t: int):
     ti = pl.program_id(2)
 
     @pl.when(ti == 0)
     def _init():
-        carry_ref[...] = h0_ref[0].astype(jnp.float32)
+        carry_ref[...] = h0_ref[...].astype(jnp.float32)
 
-    log_a = log_a_ref[0].astype(jnp.float32)     # (bt, bw)
-    b = b_ref[0].astype(jnp.float32)
+    a_ref[...] = jnp.exp(log_a_ref[0].astype(jnp.float32))   # (bt, bw)
+    x_ref[...] = b_ref[0].astype(jnp.float32)
 
-    def body(t, h):
-        h = jnp.exp(log_a[t]) * h + b[t]
-        o_ref[0, t, :] = h.astype(o_ref.dtype)
+    def body(t, h):                              # h: (1, bw)
+        row = pl.ds(t, 1)
+        h = a_ref[row, :] * h + x_ref[row, :]
+        h_ref[row, :] = h
         return h
 
     carry_ref[...] = jax.lax.fori_loop(0, block_t, body, carry_ref[...])
+    o_ref[0] = h_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_w", "interpret"))
 def rglru_scan_pallas(log_a, b, h0, *, block_t: int = DEFAULT_BLOCK_T,
-                      block_w: int = DEFAULT_BLOCK_W, interpret: bool = True):
+                      block_w: int = DEFAULT_BLOCK_W,
+                      interpret: bool | None = None):
     """log_a, b: (B,S,W); h0: (B,W).  Returns h: (B,S,W)."""
     bsz, s, w = log_a.shape
     block_t = min(block_t, s)
@@ -64,6 +74,7 @@ def rglru_scan_pallas(log_a, b, h0, *, block_t: int = DEFAULT_BLOCK_T,
         out_specs=pl.BlockSpec((1, block_t, block_w),
                                lambda b_, wi, ti: (b_, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), log_a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)] +
+        [pltpu.VMEM((block_t, block_w), jnp.float32)] * 3,
+        interpret=interpret_mode(interpret),
     )(log_a, b, h0)

@@ -33,7 +33,7 @@ import jax
 from repro.configs.registry import ARCHS, get_arch, supports_shape
 from repro.configs.shapes import SHAPES
 from repro.launch import roofline as rf
-from repro.launch.mesh import make_production_mesh, num_chips, set_mesh
+from repro.launch.mesh import make_production_mesh, num_chips
 from repro.launch.steps import build_step
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -63,7 +63,7 @@ def run_one(arch: str, shape_name: str, mesh_name: str, *,
                            remat_policy=remat_policy,
                            local_steps_in_step=local_steps or 2)
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(cfg, shape, mesh, train_mode=train_mode,
                             serve_param_mode=serve_param_mode, tcfg=tcfg)
         lowered = jax.jit(bundle.fn).lower(*bundle.args)

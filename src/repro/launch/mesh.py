@@ -14,17 +14,6 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh):
-    """Context manager activating ``mesh``, across jax versions.
-
-    jax >= 0.5 exposes ``jax.set_mesh``; on 0.4.x a ``Mesh`` is itself the
-    context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

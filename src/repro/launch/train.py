@@ -1,15 +1,17 @@
 """End-to-end PHSFL training driver (deliverable b's e2e example backend).
 
-Runs REAL training on this machine (CPU, one device — mesh (1,1) or the
-fake multi-device mesh if XLA_FLAGS is set by the caller) at a reduced scale
-of any assigned architecture, through the same make_phsfl_round code path
-the dry-run lowers for the production mesh:
+Runs REAL training on the devices JAX sees: the clients sit on the
+'data' axis of a (C, 1) mesh when there are at least C devices, and are
+vmapped on one device otherwise.  By default the architecture is cut to a
+tiny same-family variant (``ModelConfig.reduced``); ``--published-widths``
+trains the registry config as published, in its own dtype:
 
     PYTHONPATH=src python -m repro.launch.train --arch xlstm-350m \
         --rounds 20 --clients 4 --seq 128
 
 After global training it fine-tunes per-client heads (Eq. 18) and reports
-global vs personalized loss per client.
+global vs personalized loss per client.  ``main`` returns the summary dict
+it prints last.
 """
 
 from __future__ import annotations
@@ -34,7 +36,7 @@ from repro.core import (build_optimizer, init_stacked_params,
 from repro.core.comm import comm_for_lm, comm_table_for_lm
 from repro.core.hierarchy import es_assignment
 from repro.data.synthetic import synthetic_token_batch
-from repro.launch.mesh import set_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.telemetry import MetricLogger, Telemetry
 from repro.wireless import make_scheduler
@@ -70,6 +72,9 @@ def _client_round_batch(cfg, C, k, micro, seq, seed):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-350m")
+    ap.add_argument("--published-widths", action="store_true",
+                    help="train the architecture at its published widths "
+                         "and dtype (default: the tiny reduced variant)")
     ap.add_argument("--rounds", type=int, default=10)
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--local-steps", type=int, default=2)
@@ -190,12 +195,15 @@ def main(argv=None):
                     help="flush a metrics.jsonl snapshot every N rounds "
                          "(with --trace-dir)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     tel = (Telemetry(args.trace_dir, metrics_every=args.metrics_every,
                      kernels=True)
            if args.trace_dir else Telemetry.disabled())
     log = MetricLogger("train", telemetry=tel)
-    cfg = get_arch(args.arch).reduced()
+    cfg = get_arch(args.arch)
+    if not args.published_widths:
+        cfg = cfg.reduced()
     model = build_model(cfg)
     C = args.clients
     population = None
@@ -293,7 +301,7 @@ def main(argv=None):
                        seeds={"seed": args.seed},
                        extra={"arch": args.arch, "clients": C})
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if mesh.shape["data"] == C:
             round_ = make_phsfl_round(model, hcfg, tcfg, mesh,
                                       global_sync=False,
@@ -351,7 +359,9 @@ def main(argv=None):
 
         t0 = time.time()
         metrics = {"loss": float("nan")}       # already-complete resume
+        round_loss, round_s = [], []
         for r in range(start_round, args.rounds):
+            t_r = time.time()
             batch = _client_round_batch(cfg, C, args.local_steps, args.micro,
                                         args.seq, seed=args.seed + r)
             if scheduler is not None:
@@ -379,6 +389,8 @@ def main(argv=None):
                                                       batch, au, ab)
                 log.log(step=r, loss=metrics["loss"],
                         s_per_round=(time.time() - t0) / (r + 1))
+            round_loss.append(float(metrics["loss"]))   # waits for the round
+            round_s.append(time.time() - t_r)
             if (state_dir and args.ckpt_every > 0
                     and (r + 1) % args.ckpt_every == 0):
                 save_checkpoint(state_dir, r + 1, run_state(r + 1))
@@ -386,19 +398,23 @@ def main(argv=None):
                 # simulated crash for the resume smoke test: die right
                 # after this round's checkpoint, skipping the final save
                 tel.close()
-                print(json.dumps({"aborted_after_round": r + 1}))
-                return
+                out = {"aborted_after_round": r + 1}
+                print(json.dumps(out))
+                return out
 
         # ---- personalization (Eq. 18) ----
         global_params = jax.tree.map(lambda x: x[0], params)
         ft = _client_round_batch(cfg, C, 1, args.micro, args.seq, seed=777)
         ft = {k: v[:, 0] for k, v in ft.items()}       # (C, micro, ...)
-        heads, ft_losses = personalize_head_bank(model, global_params, ft,
-                                                 tcfg)
-        ev_pers = personalized_eval(model, global_params, heads, ft)
+        heads, ft_losses = jax.jit(
+            lambda p, b: personalize_head_bank(model, p, b, tcfg))(
+                global_params, ft)
+        evaluate = jax.jit(
+            lambda p, h, b: personalized_eval(model, p, h, b))
+        ev_pers = evaluate(global_params, heads, ft)
         base_head = jnp.broadcast_to(global_params["lm_head"]["w"][None],
                                      heads.shape)
-        ev_glob = personalized_eval(model, global_params, base_head, ft)
+        ev_glob = evaluate(global_params, base_head, ft)
         for c in range(C):
             log.log(client=c, global_loss=ev_glob[c],
                     personalized_loss=ev_pers[c])
@@ -411,11 +427,13 @@ def main(argv=None):
 
     tel.close()
     out = {"final_loss": float(metrics["loss"]),
-           "personalization_gain": gain}
+           "personalization_gain": gain,
+           "round_loss": round_loss, "round_s": round_s}
     if scheduler is not None:
         out["sim_time_s"] = sim_time
         out["energy_left_j_min"] = float(scheduler.energy_left.min())
     print(json.dumps(out))
+    return out
 
 
 if __name__ == "__main__":

@@ -197,10 +197,12 @@ def init_layer_cache(cfg: ModelConfig, layer_id: int, batch: int, max_len: int,
     kind = _layer_kind(cfg, layer_id)
     if kind == SLSTM:
         return xlstm_mod.init_slstm_cache(cfg, batch)
+    # recurrent conv states take ``dtype`` too: decode writes them back in
+    # the activations' dtype, and a cache that changes dtype recompiles
     if kind == MLSTM:
-        return xlstm_mod.init_mlstm_cache(cfg, batch)
+        return xlstm_mod.init_mlstm_cache(cfg, batch, dtype)
     if kind == RGLRU:
-        return rglru_mod.init_rglru_cache(cfg, batch)
+        return rglru_mod.init_rglru_cache(cfg, batch, dtype)
     if kind == MLA_ATTN:
         return mla_mod.init_mla_cache(cfg, batch, max_len, dtype)
     window = cfg.sliding_window if kind == LOCAL_ATTN else 0

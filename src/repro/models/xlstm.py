@@ -126,11 +126,13 @@ def mlstm_chunkwise(q, k, v, li, lf, carry=None, chunk: int = MLSTM_CHUNK):
         run_max = jax.lax.cummax(g, axis=1)
         M = jnp.maximum(m_prev[:, None, :], run_max)        # (B,chunk,H)
         m_t = a + M
-        # intra-chunk: D[t,s] = exp(g_s - M_t) for s <= t
+        # intra-chunk: D[t,s] = exp(g_s - M_t) for s <= t.  Mask BEFORE the
+        # exp: above the diagonal g_s - M_t can exceed f32's exp range over
+        # a long chunk, and exp's gradient there (inf * 0) would be NaN
         Dlog = g[:, None, :, :] - M[:, :, None, :]          # (B,t,s,H)
         t_idx = jnp.arange(chunk)
         causal = t_idx[None, :, None, None] >= t_idx[None, None, :, None]
-        D = jnp.where(causal, jnp.exp(Dlog), 0.0)
+        D = jnp.exp(jnp.where(causal, Dlog, -jnp.inf))
         scores = jnp.einsum("bthd,bshd->btsh", qb, kb) * D
         h_intra = jnp.einsum("btsh,bshd->bthd", scores, vb)
         n_intra = jnp.einsum("btsh,bshd->bthd", D, kb)

@@ -314,7 +314,7 @@ class CohortScheduler(ParticipationScheduler):
         cd = np.zeros(U, bool) if client_down is None else client_down
 
         spec = self._spec
-        with core.x64():
+        with core.cpu_x64():
             up, down, latency, cuts0, _, times0, _, gate1 = (
                 np.asarray(o) for o in core.cohort_stage_a(
                     spec, self._tables, self._fixed, fade, down_row,
@@ -334,7 +334,7 @@ class CohortScheduler(ParticipationScheduler):
         elif cfg.selection == "random" and cfg.participation_prob < 1.0:
             scheduled &= self._rng.random(U) < cfg.participation_prob
 
-        with core.x64():
+        with core.cpu_x64():
             out = core.cohort_stage_b(
                 spec, self._tables, self._fixed, scheduled, up, down,
                 latency, cuts0, self.energy_left, self.device.sec_per_flop,

@@ -15,11 +15,15 @@ core's contract is bit-identity to it, pinned by the U=8 property test
 
 Bit-identity strategy
 ---------------------
-* Everything runs in float64: callers wrap invocations in
-  ``jax.experimental.enable_x64()`` (see :func:`x64`), and all array
+* Everything runs in float64 on the host CPU device: callers wrap
+  invocations in :func:`cpu_x64` (``jax.enable_x64`` plus
+  ``jax.default_device`` on ``jax.devices("cpu")[0]``), and all array
   inputs arrive as host ``np.float64``/``bool``/``int`` arrays.  No
   explicit jax dtype literals appear here — weak python scalars promote
-  to the f64 inputs, exactly like numpy.
+  to the f64 inputs, exactly like numpy.  The CPU pin is part of the
+  contract: the bit-identity below is a property of CPU XLA, and a TPU
+  only emulates f64, so the core never runs on an accelerator even when
+  one is JAX's default backend.
 * Elementwise f64 arithmetic, ``argmin`` (first-minimum tie-break),
   ``nan_to_num`` defaults, and ``segment_sum`` vs
   ``np.bincount(weights=...)`` are bitwise-identical to numpy on CPU XLA
@@ -46,18 +50,30 @@ this core (the outage masks are host inputs).
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from functools import partial
 
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 
-def x64():
-    """The double-precision context every core invocation must run in."""
-    return enable_x64()
+@contextlib.contextmanager
+def cpu_x64():
+    """The context every core invocation must run in: float64 on the host
+    CPU device.  Raises if JAX exposes no CPU backend (e.g. a restrictive
+    ``JAX_PLATFORMS``) rather than letting the core land on an
+    accelerator."""
+    try:
+        cpu = jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "the cohort scheduler core runs in float64 on JAX's CPU backend, "
+            "which is not available here; include 'cpu' in JAX_PLATFORMS "
+            "(e.g. JAX_PLATFORMS=tpu,cpu)") from e
+    with jax.enable_x64(True), jax.default_device(cpu):
+        yield
 
 
 # Pipelined chunk sums replicate numpy's pairwise summation, whose simple

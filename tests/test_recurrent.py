@@ -51,6 +51,24 @@ def test_mlstm_chunk_size_invariance(chunks, rng):
                                rtol=2e-4, atol=2e-4)
 
 
+def test_mlstm_chunkwise_grad_finite_with_strong_forget(rng):
+    """Strong forgetting drives g_s - M_t above the diagonal far past exp's
+    f32 range (10 per step here); the masked entries must not turn the
+    gradient into NaN.  Published widths at seq 1024 hit this at init."""
+    b, s, h, dh = 1, 64, 1, 8
+    q = jnp.asarray(rng.normal(size=(b, s, h, dh)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(b, s, h, dh)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(b, s, h, dh)).astype(np.float32))
+    li = jnp.zeros((b, s, h), jnp.float32)
+    lf = jnp.full((b, s, h), -10.0, jnp.float32)
+
+    def loss(q_, li_, lf_):
+        return mlstm_chunkwise(q_, k, v, li_, lf_, chunk=s)[0].sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, li, lf)
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+
+
 def test_rglru_assoc_scan_matches_sequential(rng):
     b, s, w = 2, 48, 16
     log_a = -jnp.abs(jnp.asarray(rng.normal(size=(b, s, w)).astype(np.float32))) * 0.2

@@ -8,7 +8,8 @@ on a TPU:
 
 - ``pallas-triplet``       — all three files exist;
 - ``pallas-interpret``     — every ``pallas_call`` threads an ``interpret``
-  parameter (the CPU fallback this container, CI, and the tests rely on);
+  parameter (compiled on a TPU, interpreted on the CPU that CI and the
+  tests run on; ``repro.kernels.interpret_mode`` picks);
 - ``pallas-lane``          — every resolvable trailing BlockSpec tile dim
   is 1 (scalar operand) or a multiple of the 128-wide TPU lane;
 - ``pallas-divisibility``  — the wrapper guarding a tiled grid asserts the
@@ -165,8 +166,8 @@ def check_kernel_module(path: Path, rel: str, *,
                 out.append(Finding(
                     "pallas-interpret", rel, call.lineno,
                     f"pallas_call in {fn.name!r} has no interpret= "
-                    f"parameter: the kernel cannot fall back to CPU "
-                    f"(tests, CI, and this container need interpret=True)"))
+                    f"parameter: the kernel cannot run off the TPU "
+                    f"(CI and the tests run it in the Pallas interpreter)"))
             vmem_bytes = 0
             for lineno, dims in _block_shapes(call):
                 resolved = [_resolve(d, consts, defaults) for d in dims]
