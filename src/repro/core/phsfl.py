@@ -42,6 +42,7 @@ from repro.core.split import (GLOBAL_TRAIN, HSFL_TRAIN, split_spec_for,
 from repro.models.registry import Model
 from repro.optim import apply_updates, make_optimizer, masked
 from repro.sharding.rules import data_axes, params_specs
+from repro.telemetry.spans import scope
 
 
 # --------------------------------------------------------------- common ----
@@ -78,8 +79,10 @@ def _local_scan(model: Model, tcfg: TrainConfig, opt):
             loss, g = jax.value_and_grad(
                 lambda q: model.loss(q, mb, remat=tcfg.remat,
                                      remat_policy=pol))(pp)
-            upd, ss = opt.update(g, ss, pp)
-            return (apply_updates(pp, upd), ss), loss
+            with scope("optimizer"):
+                upd, ss = opt.update(g, ss, pp)
+                pp = apply_updates(pp, upd)
+            return (pp, ss), loss
 
         (p, s), losses = jax.lax.scan(local_step, (p, s), batch_c)
         return p, s, losses
@@ -150,17 +153,23 @@ def make_phsfl_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
         # ---- edge aggregation: weighted psum over clients of this ES ----
         agg_dtype = jnp.dtype(tcfg.agg_dtype)
         if mask is None:
-            p = edge_aggregate_mesh(p, au[0], agg_dtype)
+            with scope("edge_agg"):
+                p = edge_aggregate_mesh(p, au[0], agg_dtype)
             if global_sync and "pod" in mesh.axis_names:
                 # ---- global aggregation: weighted psum over edge servers --
-                p = global_aggregate_mesh(p, ab[0], agg_dtype)
+                with scope("global_agg"):
+                    p = global_aggregate_mesh(p, ab[0], agg_dtype)
         else:
             m = mask[0].astype(agg_dtype)
-            p = masked_psum_weighted(p, au[0], m, p_prev, "data", agg_dtype)
+            with scope("edge_agg"):
+                p = masked_psum_weighted(p, au[0], m, p_prev, "data",
+                                         agg_dtype)
             if global_sync and "pod" in mesh.axis_names:
                 # an ES joins the global round iff it had >= 1 participant
-                es_m = (jax.lax.psum(m, "data") > 0).astype(agg_dtype)
-                p = masked_psum_weighted(p, ab[0], es_m, p, "pod", agg_dtype)
+                with scope("global_agg"):
+                    es_m = (jax.lax.psum(m, "data") > 0).astype(agg_dtype)
+                    p = masked_psum_weighted(p, ab[0], es_m, p, "pod",
+                                             agg_dtype)
         # true mean over ALL clients (the P() out-spec otherwise surfaces
         # shard 0's local loss with the replication check disabled)
         mean_loss = losses.mean()
@@ -223,6 +232,7 @@ def make_host_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
         p, s, losses = local_scan(p, s, bc)
         return p, s, losses.mean()
 
+    @scope("edge_agg")
     def _edge(p, p_prev, au, mask):
         w = au.astype(agg_dtype).reshape(B, Ub)
         if mask is not None:
@@ -247,6 +257,7 @@ def make_host_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
 
         return jax.tree.map(agg, p, p_prev)
 
+    @scope("global_agg")
     def _global(p, ab, mask):
         wb = ab.astype(agg_dtype).reshape(B, Ub)[:, :1]      # (B, 1)
         if mask is not None:

@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_hmajor
-from repro.telemetry.kernels import kernel_probe
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -49,13 +48,4 @@ _flash_attention_core.defvjp(_fwd, _bwd)
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     softcap: float = 0.0):
     """q: (B,S,H,d); k,v: (B,S,KVH,d) — the model-zoo layout."""
-    probe = kernel_probe("flash_attention")
-    out = _flash_attention_core(q, k, v, causal, window, softcap)
-    if probe is not None:
-        B, S, H, d = q.shape
-        kv = min(window, S) if window else S
-        # QK^T and PV matmuls, 2 FLOPs/MAC; causal halves the rectangle
-        flops = 4.0 * B * H * S * kv * d * (0.5 if causal and not window
-                                            else 1.0)
-        probe.finish(out, flops=flops, arrays=(q, k, v))
-    return out
+    return _flash_attention_core(q, k, v, causal, window, softcap)

@@ -6,7 +6,6 @@ import jax
 
 from repro.kernels.mlstm_chunk.kernel import mlstm_chunk_pallas
 from repro.kernels.mlstm_chunk.ref import mlstm_ref
-from repro.telemetry.kernels import kernel_probe
 
 
 @jax.custom_vjp
@@ -27,14 +26,4 @@ _mlstm_chunk_core.defvjp(_fwd, _bwd)
 
 
 def mlstm_chunk(q, k, v, li, lf):
-    probe = kernel_probe("mlstm_chunk")
-    out = _mlstm_chunk_core(q, k, v, li, lf)
-    if probe is not None:
-        *lead, S, d = q.shape
-        B = 1
-        for n in lead:
-            B *= n
-        # intra-chunk QK^T + PV (causal halves) at 2 FLOPs/MAC
-        probe.finish(out, flops=2.0 * B * S * S * d,
-                     arrays=(q, k, v, li, lf))
-    return out
+    return _mlstm_chunk_core(q, k, v, li, lf)

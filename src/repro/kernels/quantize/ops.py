@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from repro.kernels.quantize.kernel import LANES, quantize_dequantize_pallas
 from repro.kernels.quantize.ref import quantize_dequantize_ref
-from repro.telemetry.kernels import kernel_probe
 
 
 def tensor_scale(x, qmax: int):
@@ -52,7 +51,6 @@ def quantize_dequantize(x, key, *, bits: int = 8, stochastic: bool = True,
     ``stochastic=False``, which rounds half-up).  ``use_ref`` bypasses the
     Pallas kernel for the pure-jnp oracle (same math, same bits).
     """
-    probe = kernel_probe("quantize")
     qmax = 2 ** (bits - 1) - 1
     scale = tensor_scale(x, qmax)
     flat = x.reshape(-1)
@@ -74,7 +72,4 @@ def quantize_dequantize(x, key, *, bits: int = 8, stochastic: bool = True,
         up = jnp.pad(u_flat, (0, pad)).reshape(-1, LANES)
         out = _qdq_ste(xp, up, scale, qmax)
         out = out.reshape(-1)[:n].reshape(x.shape)
-    if probe is not None:
-        # scale + round + clip + dequant per element
-        probe.finish(out, flops=4.0 * x.size, arrays=(x,))
     return out

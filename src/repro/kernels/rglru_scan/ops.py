@@ -6,7 +6,6 @@ import jax
 
 from repro.kernels.rglru_scan.kernel import rglru_scan_pallas
 from repro.kernels.rglru_scan.ref import rglru_scan_ref
-from repro.telemetry.kernels import kernel_probe
 
 
 @jax.custom_vjp
@@ -28,9 +27,4 @@ _rglru_scan_core.defvjp(_fwd, _bwd)
 
 
 def rglru_scan(log_a, b, h0):
-    probe = kernel_probe("rglru_scan")
-    out = _rglru_scan_core(log_a, b, h0)
-    if probe is not None:
-        # exp + multiply-accumulate per element of the scanned sequence
-        probe.finish(out, flops=3.0 * log_a.size, arrays=(log_a, b, h0))
-    return out
+    return _rglru_scan_core(log_a, b, h0)

@@ -197,8 +197,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     use_compile_cache()
 
-    tel = (Telemetry(args.trace_dir, metrics_every=args.metrics_every,
-                     kernels=True)
+    tel = (Telemetry(args.trace_dir, metrics_every=args.metrics_every)
            if args.trace_dir else Telemetry.disabled())
     log = MetricLogger("train", telemetry=tel)
     cfg = get_arch(args.arch)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.init_utils import dense, dense_axes, norm, norm_axes
+from repro.telemetry.spans import scope
 
 DENSE_MAX_SEQ = 4096          # longest seq for the dense path under "auto"
 Q_CHUNK = 1024
@@ -252,6 +253,7 @@ def self_attention(q, k, v, *, causal: bool = True, window: int = 0,
                            softcap=softcap)
 
 
+@scope("attention")
 def attn_apply(p, cfg: ModelConfig, x, *, window: int = 0,
                rope_theta: float = 10000.0, softcap: float = 0.0,
                positions=None, positions3=None, causal: bool = True,

@@ -18,6 +18,7 @@ from repro.models.init_utils import (dense, dense_axes, embedding,
                                      stack_axes)
 from repro.models.layers import apply_norm, mlp_apply, mlp_axes, mlp_init
 from repro.models.transformer import LOSS_CHUNK, logits_from_hidden  # reuse head
+from repro.telemetry.spans import scope
 
 
 # ------------------------------------------------------------- layers ------
@@ -109,7 +110,8 @@ def apply(params, cfg: ModelConfig, batch, *, impl: str = "auto",
     memory = encode(params, cfg, batch["source_embeds"], impl=impl,
                     remat=remat, remat_policy=remat_policy)
     from repro.models.transformer import remat_wrapper
-    x = params["embed"]["table"][batch["tokens"]]
+    with scope("embed"):
+        x = params["embed"]["table"][batch["tokens"]]
     maybe_remat = remat_wrapper(remat, remat_policy)
 
     @maybe_remat
@@ -156,7 +158,8 @@ def precompute_cross(params, cfg: ModelConfig, memory, dtype=jnp.bfloat16):
 def decode_step(params, cfg: ModelConfig, token, cache, index, *,
                 positions3=None, return_hidden: bool = False):
     """One decoder step with self KV cache + precomputed cross KV."""
-    x = params["embed"]["table"][token]
+    with scope("embed"):
+        x = params["embed"]["table"][token]
 
     def body(x, slices):
         p, sc, ck, cv = slices

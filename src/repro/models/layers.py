@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.telemetry.spans import scope
 
 
 # ---------------------------------------------------------------- norms ----
@@ -104,6 +105,7 @@ def mlp_axes():
     }
 
 
+@scope("mlp")
 def mlp_apply(p, x, act_name: str):
     act = activation(act_name)
     h = act(x @ p["gate"]["w"]) * (x @ p["up"]["w"])

@@ -31,6 +31,7 @@ from repro.models.init_utils import (dense, dense_axes, embedding,
                                      embedding_axes, norm, norm_axes,
                                      stack_axes)
 from repro.models.layers import apply_norm, mlp_apply, mlp_axes, mlp_init, softcap
+from repro.telemetry.spans import scope
 
 LOSS_CHUNK = 512  # seq chunk for the memory-bounded LM loss
 
@@ -253,6 +254,7 @@ def axes(cfg: ModelConfig):
     return ax
 
 
+@scope("embed")
 def embed_tokens(params, cfg: ModelConfig, tokens, patch_embeds=None):
     x = params["embed"]["table"][tokens]
     if cfg.embed_scale:
@@ -329,6 +331,7 @@ def logits_from_hidden(params, cfg: ModelConfig, hidden):
     return softcap(lg.astype(jnp.float32), cfg.final_logit_softcap)
 
 
+@scope("lm_loss")
 def lm_loss(params, cfg: ModelConfig, hidden, labels):
     """Memory-bounded cross-entropy: logits materialized per seq chunk."""
     b, s, d = hidden.shape

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.init_utils import dense, dense_axes, norm, norm_axes, truncated_normal
+from repro.telemetry.spans import scope
 
 MLSTM_CHUNK = 256
 
@@ -181,6 +182,7 @@ def mlstm_step(q, k, v, li, lf, carry):
     return h, (C, n, m_new)
 
 
+@scope("mlstm")
 def mlstm_block_apply(p, cfg: ModelConfig, x, *, cache=None, index=None):
     """Full mLSTM residual block.  x: (B,S,D).
 
@@ -292,6 +294,7 @@ def slstm_scan(p, cfg: ModelConfig, x, state=None):
     return jnp.moveaxis(hs, 0, 1).reshape(b, s, d).astype(x.dtype), state
 
 
+@scope("slstm")
 def slstm_block_apply(p, cfg: ModelConfig, x, *, cache=None, index=None):
     """sLSTM residual block with post-up-projection MLP."""
     from repro.models.layers import apply_norm

@@ -13,9 +13,17 @@ every one of them inspectable without perturbing a single number:
   counters/gauges/histograms.  The scheduler registers participation,
   withdrawals/backfills, goodput-vs-retransmit bits, stale-bank
   depth/age, and per-phase energy; FedSim registers round wall time, eval
-  accuracy, and live-vs-stale aggregation mass; the Pallas ops wrappers
-  (via ``telemetry.kernels``) register call counts, wall time, bytes, and
-  achieved FLOP/s.  Flushed as JSONL plus a run-end summary table.
+  accuracy, and live-vs-stale aggregation mass.  Flushed as JSONL plus a
+  run-end summary table.
+- **Spans and scopes** (``telemetry.spans``): the program's named layers.
+  Device scopes (``jax.named_scope``: ``embed``, ``mlstm``, ``slstm``,
+  ``attention``, ``mlp``, ``lm_loss``, ``optimizer``, ``edge_agg``,
+  ``global_agg``) label every operation of the jitted round, backward
+  included, so a device profile splits by layer.  Host spans
+  (``sched.cohort``, ``sched.channel``, ``sched.price``, ``sched.report``
+  inside ``CohortScheduler.step``) are off by default; with a sink
+  installed each is a ``jax.profiler.TraceAnnotation`` on the profiler's
+  clock and a ``span.<name>`` histogram of host seconds.
 - **Manifest** (``telemetry.manifest``): config hash, seeds, jax/device
   info, git SHA — who made this artifact.
 
@@ -30,13 +38,13 @@ from __future__ import annotations
 
 import os
 
-from repro.telemetry.kernels import (get_kernel_sink, kernel_probe,
-                                     set_kernel_sink)
 from repro.telemetry.manifest import (collect_manifest, config_hash,
                                       write_manifest)
 from repro.telemetry.metrics import (Counter, Gauge, Histogram,
                                      MetricsRegistry)
 from repro.telemetry.sinks import MetricLogger, json_safe
+from repro.telemetry.spans import (SCOPES, SPAN_NAMES, get_span_sink, scope,
+                                   set_span_sink, span)
 from repro.telemetry.trace import (TraceWriter, round_span_s,
                                    timeline_to_trace_events)
 
@@ -46,7 +54,8 @@ __all__ = [
     "TraceWriter", "timeline_to_trace_events", "round_span_s",
     "collect_manifest", "config_hash", "write_manifest",
     "MetricLogger", "json_safe",
-    "kernel_probe", "set_kernel_sink", "get_kernel_sink",
+    "span", "scope", "set_span_sink", "get_span_sink", "SCOPES",
+    "SPAN_NAMES",
 ]
 
 
@@ -57,9 +66,8 @@ class Telemetry:
     (streamed Chrome trace), ``<out_dir>/metrics.jsonl`` (one registry
     snapshot every ``metrics_every`` flushes), ``<out_dir>/manifest.json``
     (via :meth:`write_manifest`), ``<out_dir>/summary.txt`` (at
-    :meth:`close`).  ``kernels=True`` additionally installs the metrics
-    registry as the global Pallas-wrapper sink for the lifetime of the
-    handle.
+    :meth:`close`).  ``spans=True`` additionally installs the metrics
+    registry as the span sink (:func:`set_span_sink`) until :meth:`close`.
 
     ``Telemetry.disabled()`` is the OFF state every entry point defaults
     to: ``enabled`` is False and :meth:`record_round` / :meth:`flush` /
@@ -67,7 +75,7 @@ class Telemetry:
     """
 
     def __init__(self, out_dir: str | None = None, *, trace: bool = True,
-                 metrics_every: int = 1, kernels: bool = False,
+                 metrics_every: int = 1, spans: bool = False,
                  _enabled: bool = True):
         self.enabled = bool(_enabled)
         self.out_dir = out_dir
@@ -76,7 +84,7 @@ class Telemetry:
         self.trace = None
         self._metrics_fh = None
         self._flushes = 0
-        self._owns_kernel_sink = False
+        self._owns_span_sink = False
         self._closed = False
         if not self.enabled:
             return
@@ -87,9 +95,9 @@ class Telemetry:
                                                       "trace.json"))
             self._metrics_fh = open(os.path.join(out_dir, "metrics.jsonl"),
                                     "w")
-        if kernels:
-            set_kernel_sink(self.metrics)
-            self._owns_kernel_sink = True
+        if spans:
+            set_span_sink(self.metrics)
+            self._owns_span_sink = True
 
     _DISABLED = None
 
@@ -178,8 +186,8 @@ class Telemetry:
         if self._closed:
             return self.summary()
         self._closed = True
-        if self._owns_kernel_sink and get_kernel_sink() is self.metrics:
-            set_kernel_sink(None)
+        if self._owns_span_sink and get_span_sink() is self.metrics:
+            set_span_sink(None)
         table = self.summary()
         if self._metrics_fh is not None:
             self.metrics.flush_jsonl(self._metrics_fh, step=None)

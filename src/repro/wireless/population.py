@@ -43,6 +43,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.configs.base import WirelessConfig
+from repro.telemetry.spans import span
 from repro.wireless.channel import LinkState
 from repro.wireless.scheduler import ParticipationScheduler, RoundReport
 from repro.wireless import scheduler_core as core
@@ -227,6 +228,14 @@ class CohortScheduler(ParticipationScheduler):
     ``last_timeline`` is populated only when telemetry is enabled: the
     explicit per-client event timeline is O(N x chunks) host memory, which
     is precisely the cost this class exists to avoid.
+
+    With a span sink installed (``repro.telemetry.set_span_sink``) the
+    vectorized step opens four host spans: ``sched.cohort`` (the cohort
+    draw), ``sched.channel`` (the channel's fade draw), ``sched.price``
+    (both float64 stages with the selection gate and the top-k backfill
+    between them) and ``sched.report`` (the report and the stale-bank
+    update).  The fault-plan fallback to ``super().step()`` opens none of
+    its own: such a round shows only ``sched.cohort``, drawn before it.
     """
 
     def __init__(self, cfg: WirelessConfig, channel, bits=None, *,
@@ -279,7 +288,8 @@ class CohortScheduler(ParticipationScheduler):
     # ---------------------------------------------------------- stepping --
     def step(self, round_idx: int) -> RoundReport:
         if self.population is not None and self._cohort is None:
-            self.sample_cohort()
+            with span("sched.cohort"):
+                self.sample_cohort()
         self.last_cohort, self._cohort = self._cohort, None
         if self.injector is not None and self.injector.needs_plan:
             # erasure/crash rounds: data-dependent HARQ attempt shapes —
@@ -306,7 +316,8 @@ class CohortScheduler(ParticipationScheduler):
 
         # ---- host entropy: the channel's per-round draw (same stream,
         # same consumption as the oracle's sample())
-        fade, down_row = self.channel.fades(round_idx)
+        with span("sched.channel"):
+            fade, down_row = self.channel.fades(round_idx)
         if fade is None:
             fade = np.ones(U)                      # ideal: unused in-trace
         if down_row is None:
@@ -314,108 +325,113 @@ class CohortScheduler(ParticipationScheduler):
         cd = np.zeros(U, bool) if client_down is None else client_down
 
         spec = self._spec
-        with core.cpu_x64():
-            up, down, latency, cuts0, _, times0, _, gate1 = (
-                np.asarray(o) for o in core.cohort_stage_a(
-                    spec, self._tables, self._fixed, fade, down_row,
-                    self.channel._scale, self.device.sec_per_flop,
-                    self.energy_left, cd))
-        if self.cohort_mask is not None:
-            gate1 = gate1 & self.cohort_mask
+        with span("sched.price"):
+            with core.cpu_x64():
+                up, down, latency, cuts0, _, times0, _, gate1 = (
+                    np.asarray(o) for o in core.cohort_stage_a(
+                        spec, self._tables, self._fixed, fade, down_row,
+                        self.channel._scale, self.device.sec_per_flop,
+                        self.energy_left, cd))
+            if self.cohort_mask is not None:
+                gate1 = gate1 & self.cohort_mask
 
-        # ---- selection gate (host: np.argsort's quicksort tie order and
-        # the thinning stream are host semantics, on bit-identical times0)
-        scheduled = gate1.copy()
-        if cfg.selection == "topk" and cfg.topk > 0:
-            order = np.argsort(np.where(scheduled, times0, np.inf))
-            keep = np.zeros(U, bool)
-            keep[order[:cfg.topk]] = True
-            scheduled &= keep
-        elif cfg.selection == "random" and cfg.participation_prob < 1.0:
-            scheduled &= self._rng.random(U) < cfg.participation_prob
+            # ---- selection gate (host: np.argsort's quicksort tie order and
+            # the thinning stream are host semantics, on bit-identical times0)
+            scheduled = gate1.copy()
+            if cfg.selection == "topk" and cfg.topk > 0:
+                order = np.argsort(np.where(scheduled, times0, np.inf))
+                keep = np.zeros(U, bool)
+                keep[order[:cfg.topk]] = True
+                scheduled &= keep
+            elif cfg.selection == "random" and cfg.participation_prob < 1.0:
+                scheduled &= self._rng.random(U) < cfg.participation_prob
 
-        with core.cpu_x64():
-            out = core.cohort_stage_b(
-                spec, self._tables, self._fixed, scheduled, up, down,
-                latency, cuts0, self.energy_left, self.device.sec_per_flop,
-                self._es_eff)
-            sched = np.asarray(out[4])
-            n_backfilled = 0
-            if (spec.contend and cfg.selection == "topk" and cfg.topk > 0
-                    and int(sched.sum()) < cfg.topk):
-                # topk backfill (single pass): promote the next-fastest
-                # never-withdrawn clients and re-run the pure contention
-                # stage from the ORIGINAL private-rate cuts
-                withdrawn = np.asarray(out[5])
-                pool = gate1 & ~sched & ~withdrawn
-                if pool.any():
-                    order = np.argsort(np.where(pool, times0, np.inf))
-                    extra = np.zeros(U, bool)
-                    extra[order[:cfg.topk - int(sched.sum())]] = True
-                    extra &= pool
-                    if extra.any():
-                        out = core.cohort_stage_b(
-                            spec, self._tables, self._fixed, sched | extra,
-                            up, down, latency, cuts0, self.energy_left,
-                            self.device.sec_per_flop, self._es_eff)
-                        sched = np.asarray(out[4])
-                        n_backfilled = int((sched & extra).sum())
-        (eff, cuts, comp_s, times, _, withdrawn, alive, energy_after,
-         moved_up, moved_down, compute_j, tx_s, _) = (
-            np.asarray(o) for o in out)
+            with core.cpu_x64():
+                out = core.cohort_stage_b(
+                    spec, self._tables, self._fixed, scheduled, up, down,
+                    latency, cuts0, self.energy_left, self.device.sec_per_flop,
+                    self._es_eff)
+                sched = np.asarray(out[4])
+                n_backfilled = 0
+                if (spec.contend and cfg.selection == "topk" and cfg.topk > 0
+                        and int(sched.sum()) < cfg.topk):
+                    # topk backfill (single pass): promote the next-fastest
+                    # never-withdrawn clients and re-run the pure contention
+                    # stage from the ORIGINAL private-rate cuts
+                    withdrawn = np.asarray(out[5])
+                    pool = gate1 & ~sched & ~withdrawn
+                    if pool.any():
+                        order = np.argsort(np.where(pool, times0, np.inf))
+                        extra = np.zeros(U, bool)
+                        extra[order[:cfg.topk - int(sched.sum())]] = True
+                        extra &= pool
+                        if extra.any():
+                            out = core.cohort_stage_b(
+                                spec, self._tables, self._fixed,
+                                sched | extra, up, down, latency, cuts0,
+                                self.energy_left, self.device.sec_per_flop,
+                                self._es_eff)
+                            sched = np.asarray(out[4])
+                            n_backfilled = int((sched & extra).sum())
+            (eff, cuts, comp_s, times, _, withdrawn, alive, energy_after,
+             moved_up, moved_down, compute_j, tx_s, _) = (
+                np.asarray(o) for o in out)
 
-        self.energy_left = energy_after
-        if not alive.any():
-            round_time = (float(cfg.deadline_s)
-                          if sched.any() and np.isfinite(cfg.deadline_s)
-                          else 0.0)
-        elif (sched & ~alive).any():
-            round_time = float(cfg.deadline_s)
-        else:
-            t = times[alive].max()
-            round_time = float(t) if np.isfinite(t) else 0.0
+        with span("sched.report"):
+            self.energy_left = energy_after
+            if not alive.any():
+                round_time = (float(cfg.deadline_s)
+                              if sched.any() and np.isfinite(cfg.deadline_s)
+                              else 0.0)
+            elif (sched & ~alive).any():
+                round_time = float(cfg.deadline_s)
+            else:
+                t = times[alive].max()
+                round_time = float(t) if np.isfinite(t) else 0.0
 
-        rep_cuts = rep_codecs = None
-        up_bits = None
-        if self.cutter is not None:
-            rep_cuts = self.cutter.cut_pos[cuts]
-            if self.cutter.has_codec_grid:
-                rep_codecs = self.cutter.codec_pos[cuts]
-            up_bits = np.asarray(self.cutter.up_bits, float)[cuts]
-        else:
-            up_bits = np.broadcast_to(
-                np.asarray(self.bits.uplink, float), (U,))
-        moved = moved_up + moved_down
-        bits_tx = float(moved[sched].sum())
+            rep_cuts = rep_codecs = None
+            up_bits = None
+            if self.cutter is not None:
+                rep_cuts = self.cutter.cut_pos[cuts]
+                if self.cutter.has_codec_grid:
+                    rep_codecs = self.cutter.codec_pos[cuts]
+                up_bits = np.asarray(self.cutter.up_bits, float)[cuts]
+            else:
+                up_bits = np.broadcast_to(
+                    np.asarray(self.bits.uplink, float), (U,))
+            moved = moved_up + moved_down
+            bits_tx = float(moved[sched].sum())
 
-        stale_banked = stale_delivered = stale_dropped = None
-        if cfg.staleness_lambda > 0.0:
-            private = LinkState(up, down, latency)
-            stale_banked, stale_delivered, stale_dropped, bg_bits = \
-                self._stale_update(
-                    private, sched, alive, up_bits, moved_up, round_time,
-                    push_ok=(None if es_down is None
-                             else ~es_down[self._es_eff]),
-                    bankable=None)
-            bits_tx += bg_bits
+            stale_banked = stale_delivered = stale_dropped = None
+            if cfg.staleness_lambda > 0.0:
+                private = LinkState(up, down, latency)
+                stale_banked, stale_delivered, stale_dropped, bg_bits = \
+                    self._stale_update(
+                        private, sched, alive, up_bits, moved_up,
+                        round_time,
+                        push_ok=(None if es_down is None
+                                 else ~es_down[self._es_eff]),
+                        bankable=None)
+                bits_tx += bg_bits
 
-        es_map = (self._es_eff.copy()
-                  if es_down is not None
-                  and not np.array_equal(self._es_eff, self.es_assign)
-                  else None)
-        rep = RoundReport(round_idx=round_idx, mask=alive.astype(np.float64),
-                          times_s=times, round_time_s=round_time,
-                          energy_left_j=self.energy_left.copy(),
-                          scheduled=sched.copy(), cuts=rep_cuts,
-                          uplink_bps=eff.copy(), codecs=rep_codecs,
-                          bits_tx=bits_tx,
-                          compute_s=comp_s.copy(), compute_j=compute_j,
-                          stale_banked=stale_banked,
-                          stale_delivered=stale_delivered,
-                          stale_dropped=stale_dropped,
-                          es_down=None if es_down is None
-                          else es_down.copy(),
-                          es_map=es_map)
+            es_map = (self._es_eff.copy()
+                      if es_down is not None
+                      and not np.array_equal(self._es_eff, self.es_assign)
+                      else None)
+            rep = RoundReport(round_idx=round_idx,
+                              mask=alive.astype(np.float64),
+                              times_s=times, round_time_s=round_time,
+                              energy_left_j=self.energy_left.copy(),
+                              scheduled=sched.copy(), cuts=rep_cuts,
+                              uplink_bps=eff.copy(), codecs=rep_codecs,
+                              bits_tx=bits_tx,
+                              compute_s=comp_s.copy(), compute_j=compute_j,
+                              stale_banked=stale_banked,
+                              stale_delivered=stale_delivered,
+                              stale_dropped=stale_dropped,
+                              es_down=None if es_down is None
+                              else es_down.copy(),
+                              es_map=es_map)
         self.last_timeline = None
         tel = self.telemetry
         if tel is not None and getattr(tel, "enabled", False):

@@ -30,8 +30,7 @@ from repro.configs.base import FaultConfig, WirelessConfig
 from repro.telemetry import (Counter, Gauge, Histogram, MetricLogger,
                              MetricsRegistry, Telemetry, TraceWriter,
                              collect_manifest, config_hash, json_safe,
-                             kernel_probe, round_span_s, set_kernel_sink,
-                             timeline_to_trace_events)
+                             round_span_s, timeline_to_trace_events)
 from repro.wireless import make_scheduler
 from repro.wireless.channel import LinkState, RoundBits
 from repro.wireless.faults import FaultPlan
@@ -360,47 +359,6 @@ class TestSchedulerTelemetry:
             assert ra.bits_tx == rb.bits_tx
             np.testing.assert_array_equal(ra.mask, rb.mask)
             np.testing.assert_array_equal(ra.energy_left_j, rb.energy_left_j)
-
-
-# --------------------------------------------------------- kernel probes
-class TestKernelProbes:
-    def teardown_method(self):
-        set_kernel_sink(None)
-
-    def test_no_sink_zero_overhead_path(self):
-        assert kernel_probe("x") is None
-
-    def test_concrete_call_records(self):
-        from repro.kernels.quantize.ops import quantize_dequantize
-        reg = MetricsRegistry()
-        set_kernel_sink(reg)
-        x = jnp.arange(16.0).reshape(4, 4)
-        quantize_dequantize(x, jax.random.PRNGKey(0), bits=8)
-        snap = reg.snapshot()
-        assert snap["kernel.quantize.calls"]["value"] == 1
-        assert snap["kernel.quantize.flops"]["value"] == 4.0 * 16
-        assert snap["kernel.quantize.bytes"]["value"] > 0
-        assert snap["kernel.quantize.wall_s"]["count"] == 1
-
-    def test_traced_call_counted_not_timed(self):
-        from repro.kernels.quantize.ops import quantize_dequantize
-        reg = MetricsRegistry()
-        set_kernel_sink(reg)
-        f = jax.jit(lambda x, k: quantize_dequantize(x, k, bits=8))
-        x = jnp.arange(16.0).reshape(4, 4)
-        f(x, jax.random.PRNGKey(0))
-        snap = reg.snapshot()
-        assert snap["kernel.quantize.traced_calls"]["value"] >= 1
-        assert "kernel.quantize.wall_s" not in snap
-
-    def test_numerics_identical_with_probe(self):
-        from repro.kernels.quantize.ops import quantize_dequantize
-        x = jnp.linspace(-1, 1, 64)
-        k = jax.random.PRNGKey(3)
-        base = quantize_dequantize(x, k, bits=4)
-        set_kernel_sink(MetricsRegistry())
-        probed = quantize_dequantize(x, k, bits=4)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(probed))
 
 
 # ------------------------------------------------------------- manifest
