@@ -8,7 +8,9 @@ recurrent form.  The two are numerically consistent (tested).
 
 sLSTM is inherently sequential (hidden state feeds the gates); training is a
 lax.scan over time — this is honest to the architecture and shows up as a
-latency-bound term in the roofline analysis.
+latency-bound term in the roofline analysis.  Its backward is written by
+hand: the reverse time loop carries only the state cotangents, and the
+recurrent weight's gradient is one matmul over all steps after it.
 """
 
 from __future__ import annotations
@@ -254,15 +256,10 @@ def slstm_axes(cfg: ModelConfig):
     }
 
 
-def _slstm_cell(p, cfg: ModelConfig, wx_t, state):
-    """One sLSTM step.  wx_t: (B,H,4*dh) precomputed W x_t (+ b).
-
-    state: (c, n, h, m) each (B,H,dh) except m (B,H,dh? scalar-per-unit) —
-    xLSTM stabilizer is per *unit*: keep (B,H,dh).
-    """
-    c, n, hid, m = state
-    rh = jnp.einsum("bhd,hdk->bhk", hid.astype(wx_t.dtype), p["r"])
-    raw = (wx_t + rh).astype(jnp.float32)
+def _slstm_pointwise(raw, c, n, m):
+    """The cell after its pre-activations.  raw: (B,H,4*dh) f32, the gates'
+    inputs [i ; f ; z ; o]; c, n, m: (B,H,dh) previous state (the xLSTM
+    stabilizer m is per unit).  Returns the next (c, n, h, m)."""
     dh = c.shape[-1]
     i_t, f_t, z_t, o_t = (raw[..., j * dh:(j + 1) * dh] for j in range(4))
     lf = jax.nn.log_sigmoid(f_t)
@@ -272,7 +269,57 @@ def _slstm_cell(p, cfg: ModelConfig, wx_t, state):
     c_new = fgate * c + igate * jnp.tanh(z_t)
     n_new = fgate * n + igate
     h_new = jax.nn.sigmoid(o_t) * c_new / jnp.maximum(n_new, 1e-6)
-    return (c_new, n_new, h_new, m_new), h_new
+    return c_new, n_new, h_new, m_new
+
+
+def _slstm_steps(r, wx, state):
+    """The recurrence over time.  wx: (S,B,H,4*dh) f32, W x_t + b time-major;
+    r: (H,dh,4*dh); state: (c, n, h, m) each (B,H,dh).  Returns
+    (hs (S,B,H,dh), final state, per-step residuals (raw, c, n, m))."""
+    def body(st, wx_t):
+        c, n, hid, m = st
+        raw = wx_t + jnp.einsum("bhd,hdk->bhk", hid, r)
+        new = _slstm_pointwise(raw, c, n, m)
+        return new, (new[2], (raw, c, n, m))
+
+    state, (hs, saved) = jax.lax.scan(body, state, wx)
+    return hs, state, saved
+
+
+@jax.custom_vjp
+def _slstm_recurrence(r, wx, state):
+    hs, state, _ = _slstm_steps(r, wx, state)
+    return hs, state
+
+
+def _slstm_recurrence_fwd(r, wx, state):
+    hs, final, saved = _slstm_steps(r, wx, state)
+    return (hs, final), (r, saved, hs, state[2])
+
+
+def _slstm_recurrence_bwd(res, cts):
+    """Reverse time loop carrying only the state cotangents; R's gradient is
+    one matmul over all steps afterwards, not a per-step accumulation."""
+    r, (raws, cs, ns, ms), hs, h0 = res
+    d_hs, d_final = cts
+
+    def body(d_state, xs):
+        raw, c, n, m, d_h = xs
+        dc, dn, dh, dm = d_state
+        _, pointwise_vjp = jax.vjp(_slstm_pointwise, raw, c, n, m)
+        d_raw, dc, dn, dm = pointwise_vjp((dc, dn, dh + d_h, dm))
+        dh = jnp.einsum("bhk,hdk->bhd", d_raw, r)
+        return (dc, dn, dh, dm), d_raw
+
+    d_state, d_raws = jax.lax.scan(body, d_final, (raws, cs, ns, ms, d_hs),
+                                   reverse=True)
+    h_prev = jnp.concatenate([h0[None], hs[:-1]])
+    dr = jnp.einsum("sbhd,sbhk->hdk", h_prev, d_raws,
+                    preferred_element_type=jnp.float32)
+    return dr.astype(r.dtype), d_raws, d_state
+
+
+_slstm_recurrence.defvjp(_slstm_recurrence_fwd, _slstm_recurrence_bwd)
 
 
 def slstm_scan(p, cfg: ModelConfig, x, state=None):
@@ -287,10 +334,7 @@ def slstm_scan(p, cfg: ModelConfig, x, state=None):
         z = lambda: jnp.zeros((b, h, dh), jnp.float32)
         state = (z(), z(), z(), jnp.full((b, h, dh), -1e30, jnp.float32))
 
-    def body(st, wx_t):
-        return _slstm_cell(p, cfg, wx_t, st)
-
-    state, hs = jax.lax.scan(body, state, jnp.moveaxis(wx, 1, 0))
+    hs, state = _slstm_recurrence(p["r"], jnp.moveaxis(wx, 1, 0), state)
     return jnp.moveaxis(hs, 0, 1).reshape(b, s, d).astype(x.dtype), state
 
 
