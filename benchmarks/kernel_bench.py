@@ -35,7 +35,8 @@ def bench_rows() -> list[tuple[str, float, str]]:
     t_ker = _time(lambda: flash_attention_hmajor(q, k, v, causal=True,
                                                  block_q=128, block_k=128))
     err = float(jnp.abs(
-        flash_attention_hmajor(q, k, v, causal=True, block_q=128, block_k=128)
+        flash_attention_hmajor(q, k, v, causal=True, block_q=128,
+                               block_k=128)[0]
         - attention_ref(q, k, v, causal=True)).max())
     rows.append(("flash_attention_interp", t_ker, f"ref_us={t_ref:.0f};max_err={err:.1e}"))
 

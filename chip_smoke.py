@@ -134,8 +134,8 @@ def _kernels(jax):
 
     b, h, kvh, s, d = FLASH
     q, k, v = (normal((b, n, s, d), jnp.bfloat16) for n in (h, kvh, kvh))
-    out = compiled_kernel(jax, "flash_attention", flash_attention_hmajor,
-                          q, k, v)
+    out, _ = compiled_kernel(jax, "flash_attention", flash_attention_hmajor,
+                             q, k, v)
     ref = attention_ref(q, k, v, causal=True)
     check_close("flash_attention", out, ref, atol=2e-2, rtol=2e-2,
                 why="bf16 output: one bf16 rounding (2^-8) of values of "

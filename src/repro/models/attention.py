@@ -1,15 +1,24 @@
-"""GQA attention with dense, chunked (flash-style online-softmax) and banded
-(sliding-window) pure-JAX paths, plus KV-cache prefill/decode.
+"""GQA attention: the Pallas flash kernel on a TPU, and dense, chunked
+(online-softmax) and banded (sliding-window) pure-JAX paths, plus KV-cache
+prefill/decode.
 
-Path selection (``impl="auto"``):
-  - decode (q_len == 1): dense dot over the cache (memory-bound anyway).
-  - short sequences: dense masked softmax.
-  - long sequences, full attention: chunked online softmax (O(chunk) memory).
-  - long sequences, sliding window: banded — each query chunk only touches
-    its (chunk + window) key band, so FLOPs are O(S*w) not O(S^2).
-
-The Pallas flash kernel (repro.kernels.flash_attention) implements the same
-contract with proper block skipping on TPU; ``impl="flash"`` routes there.
+Path selection for full-sequence attention (``impl="auto"``,
+:func:`choose_impl`, from the shapes and the platform alone):
+  - on a TPU, query and key lengths both multiples of ``FLASH_TILE`` and
+    at least ``FLASH_MIN_SEQ``: the flash kernel
+    (``repro.kernels.flash_attention``).  Bidirectional, causal and
+    cross-attention (Sq != Skv) alike; its backward pass keeps O(Sq) per
+    head (the output and each row's log-sum-exp), never the Sq x Skv
+    probabilities, so training memory does not grow with S^2.  Below
+    ``FLASH_MIN_SEQ`` both paths take the same time per call on a v5e and
+    the probabilities are small, so the dense path stays.
+  - otherwise (decode with q_len 1, other platforms, short or odd
+    lengths): short sequences take the dense masked softmax; long ones,
+    full attention: chunked online softmax (O(chunk) memory); long ones,
+    sliding window: banded — each query chunk only touches its (chunk +
+    window) key band, so FLOPs are O(S*w) not O(S^2).
+``impl="flash"``, ``"dense"`` or ``"chunked"`` (chunked, or banded with a
+window) force a path.
 """
 
 from __future__ import annotations
@@ -25,6 +34,8 @@ from repro.models.init_utils import dense, dense_axes, norm, norm_axes
 from repro.telemetry.spans import scope
 
 DENSE_MAX_SEQ = 4096          # longest seq for the dense path under "auto"
+FLASH_TILE = 128              # the flash path's length unit: one lane tile
+FLASH_MIN_SEQ = 512           # shortest length the flash path takes on a TPU
 Q_CHUNK = 1024
 KV_CHUNK = 1024
 
@@ -139,7 +150,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int, softcap: float,
     """Flash-style online-softmax attention, O(chunk^2) live memory.
 
     Full-rectangle compute with masking (no block skipping — the Pallas
-    kernel does skipping on TPU; see DESIGN.md §Perf).
+    kernel does skipping on TPU).
     """
     b, sq, h, d = q.shape
     sk, kv_heads = k.shape[1], k.shape[2]
@@ -233,20 +244,31 @@ def banded_attention(q, k, v, *, window: int, softcap: float,
     return jnp.moveaxis(out, 0, 1).reshape(b, sq, h, d).astype(q.dtype)
 
 
+def choose_impl(sq: int, sk: int, platform: str) -> str:
+    """The path ``impl="auto"`` takes for ``sq`` queries onto ``sk`` keys on
+    ``platform`` (``jax.default_backend()``)."""
+    if (platform == "tpu" and min(sq, sk) >= FLASH_MIN_SEQ
+            and sq % FLASH_TILE == 0 and sk % FLASH_TILE == 0):
+        return "flash"
+    if sq <= DENSE_MAX_SEQ or sq % Q_CHUNK or sk % KV_CHUNK:
+        return "dense"
+    return "chunked"
+
+
 def self_attention(q, k, v, *, causal: bool = True, window: int = 0,
                    softcap: float = 0.0, impl: str = "auto"):
-    """Full-sequence self-attention with automatic path choice."""
+    """Full-sequence attention of q (B,Sq,H,d) onto k, v (B,Skv,KV,d),
+    with automatic path choice (:func:`choose_impl`)."""
     sq = q.shape[1]
+    if impl == "auto":
+        impl = choose_impl(sq, k.shape[1], jax.default_backend())
     if impl == "flash":
         from repro.kernels.flash_attention.ops import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap)
-    if impl == "dense" or (impl == "auto" and sq <= DENSE_MAX_SEQ):
-        return dense_attention(q, k, v, causal=causal, window=window,
-                               softcap=softcap)
-    if window and sq % Q_CHUNK == 0:
+    if impl == "chunked" and window and sq == k.shape[1]:
         return banded_attention(q, k, v, window=window, softcap=softcap)
-    if sq % Q_CHUNK == 0:
+    if impl == "chunked":
         return chunked_attention(q, k, v, causal=causal, window=window,
                                  softcap=softcap)
     return dense_attention(q, k, v, causal=causal, window=window,

@@ -86,7 +86,8 @@ def axes(cfg: ModelConfig):
 def encode(params, cfg: ModelConfig, source_embeds, *, impl: str = "auto",
            remat: bool = False, remat_policy: str | None = None):
     from repro.models.transformer import remat_wrapper
-    x = source_embeds @ params["src_proj"]["w"]
+    w = params["src_proj"]["w"]
+    x = source_embeds.astype(w.dtype) @ w          # activations in the params' dtype
     maybe_remat = remat_wrapper(remat, remat_policy)
 
     @maybe_remat
@@ -120,9 +121,11 @@ def apply(params, cfg: ModelConfig, batch, *, impl: str = "auto",
         x = x + attn_mod.attn_apply(p["self"], cfg, h, causal=True,
                                     rope_theta=cfg.rope_theta, impl=impl)
         h = apply_norm(p["lnx"], x, cfg.norm)
-        kv = attn_mod.cross_kv(p["cross"], cfg, memory)
-        x = x + attn_mod.attn_apply(p["cross"], cfg, h, causal=False,
+        with scope("cross_attention"):
+            kv = attn_mod.cross_kv(p["cross"], cfg, memory)
+            y = attn_mod.attn_apply(p["cross"], cfg, h, causal=False,
                                     rope_theta=0.0, kv_override=kv, impl=impl)
+        x = x + y
         h = apply_norm(p["ln2"], x, cfg.norm)
         return x + mlp_apply(p["mlp"], h, cfg.act), None
 

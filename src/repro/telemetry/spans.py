@@ -31,7 +31,7 @@ import time
 # the device scopes of the jitted round; a reader charges an operation to
 # the outermost of them in its name stack
 SCOPES = ("edge_agg", "global_agg", "optimizer", "lm_loss", "embed",
-          "mlstm", "slstm", "attention", "mlp")
+          "mlstm", "slstm", "attention", "mlp", "cross_attention")
 
 # the host spans of CohortScheduler.step
 SPAN_NAMES = ("sched.cohort", "sched.channel", "sched.price", "sched.report")

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 from repro.configs.registry import get_arch
 from repro.data.synthetic import synthetic_token_batch
 from repro.models import build_model
-from repro.models.attention import (banded_attention, chunked_attention,
-                                    dense_attention)
+from repro.models.attention import (banded_attention, choose_impl,
+                                    chunked_attention, dense_attention,
+                                    self_attention)
 from repro.models.layers import apply_mrope, apply_rope
 
 
@@ -37,6 +38,63 @@ def test_banded_matches_dense(rng):
     out = banded_attention(q, k, v, window=64, softcap=0.0, q_chunk=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("sq,sk,platform,impl", [
+    # m4t-medium-round on a TPU: the encoder, the decoder, cross-attention
+    (1024, 1024, "tpu", "flash"),
+    (512, 512, "tpu", "flash"),
+    (512, 1024, "tpu", "flash"),
+    (32768, 32768, "tpu", "flash"),
+    # decode, lengths off the kernel's 128-row unit, and short lengths,
+    # where dense is the faster path
+    (1, 1024, "tpu", "dense"),
+    (128, 128, "tpu", "dense"),
+    (256, 256, "tpu", "dense"),
+    (256, 1024, "tpu", "dense"),
+    (1, 1, "tpu", "dense"),
+    (64, 64, "tpu", "dense"),
+    (1000, 1000, "tpu", "dense"),
+    # other platforms keep the pure-JAX paths
+    (1024, 1024, "cpu", "dense"),
+    (512, 1024, "gpu", "dense"),
+    (8192, 8192, "cpu", "chunked"),
+    (8192, 8000, "cpu", "dense"),
+])
+def test_choose_impl(sq, sk, platform, impl):
+    assert choose_impl(sq, sk, platform) == impl
+
+
+@pytest.mark.parametrize("causal,sk", [(True, 64), (False, 64), (False, 128)])
+def test_auto_on_the_cpu_is_dense(causal, sk, rng):
+    """Under ``impl="auto"`` off a TPU the tiny shapes run the dense path,
+    bit for bit, cross-attention (Sq != Skv) included."""
+    q = jnp.asarray(rng.normal(size=(2, 64, 4, 16)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(2, sk, 2, 16)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(2, sk, 2, 16)).astype(np.float32))
+    out = self_attention(q, k, v, causal=causal)
+    ref = dense_attention(q, k, v, causal=causal, window=0, softcap=0.0)
+    assert np.array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("causal,sk", [(True, 64), (False, 128)])
+def test_forced_flash_matches_dense(causal, sk, rng):
+    """``impl="flash"`` (the kernel in the Pallas interpreter here) gives
+    the dense path's output and gradients."""
+    q = jnp.asarray(rng.normal(size=(1, 64, 4, 16)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(1, sk, 2, 16)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(1, sk, 2, 16)).astype(np.float32))
+
+    def loss(impl):
+        return lambda *a: (self_attention(*a, causal=causal, impl=impl)
+                           ** 2).sum()
+
+    for impl_out, ref_out in zip(
+            jax.value_and_grad(loss("flash"), argnums=(0, 1, 2))(q, k, v),
+            jax.value_and_grad(loss("dense"), argnums=(0, 1, 2))(q, k, v)):
+        for a, b in zip(jax.tree.leaves(impl_out), jax.tree.leaves(ref_out)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
 
 
 def test_rope_relative_shift_invariance(rng):

@@ -177,6 +177,11 @@ def _tiny_round(arch, global_sync):
     nb = synthetic_token_batch(0, C * K * MICRO, SEQ, cfg.vocab_size)
     batch = {k: jnp.asarray(v).reshape(C, K, MICRO, SEQ)
              for k, v in nb.items()}
+    if cfg.encdec is not None:
+        batch["source_embeds"] = jnp.asarray(
+            np.random.default_rng(0).standard_normal(
+                (C, K, MICRO, cfg.encdec.max_source_len, cfg.d_model)),
+            jnp.float32)
     r = make_host_round(model, hcfg, tcfg, num_clients=C,
                         global_sync=global_sync, participation=True)
     args = (params, opt_state, batch, jnp.full((C,), 1.0 / C),
@@ -192,9 +197,10 @@ def _op_names(fn, args):
 @pytest.fixture(scope="module")
 def round_op_names():
     """op_name metadata of the compiled tiny xLSTM round (with global
-    aggregation) and of a tiny attention/MLP transformer's."""
+    aggregation), of a tiny attention/MLP transformer's and of a tiny
+    encoder-decoder's (cross-attention)."""
     names = []
-    for arch in ("xlstm-350m", "gemma3-12b"):
+    for arch in ("xlstm-350m", "gemma3-12b", "seamless-m4t-medium"):
         names += _op_names(*_tiny_round(arch, global_sync=True))
     return names
 
@@ -213,11 +219,28 @@ def test_each_scope_names_ops_of_the_compiled_round(round_op_names, name):
     assert any(name in _components(s) for s in round_op_names), name
 
 
-@pytest.mark.parametrize("name", ["slstm", "mlstm", "lm_loss"])
+def _direction(stack, name):
+    """"bwd" or "fwd" where ``name`` is a component of the stack under a
+    transposed or a differentiated component (itself or one before it,
+    the rule of ``bench/scopes.py``), else None.  A layer-scanned block's
+    stack reads ``transpose(jvp())/while/body/closed_call/<scope>/...``;
+    an unscanned one's ``transpose(jvp(<scope>))/...``."""
+    transposed = differentiated = False
+    for comp in stack.split(";", 1)[0].split("/"):
+        while m := re.fullmatch(r"([\w.\-]*)\((.*)\)", comp):
+            transposed |= m.group(1) == "transpose"
+            differentiated |= m.group(1) == "jvp"
+            comp = m.group(2)
+        if comp == name:
+            return "bwd" if transposed else "fwd" if differentiated else None
+    return None
+
+
+@pytest.mark.parametrize("name", ["slstm", "mlstm", "lm_loss",
+                                  "cross_attention"])
 def test_backward_ops_carry_their_scope(round_op_names, name):
-    bwd = [s for s in round_op_names if f"transpose(jvp({name}))" in s]
-    fwd = [s for s in round_op_names
-           if f"jvp({name})" in s and "transpose(" not in s]
+    bwd = [s for s in round_op_names if _direction(s, name) == "bwd"]
+    fwd = [s for s in round_op_names if _direction(s, name) == "fwd"]
     assert bwd and fwd, name
 
 
@@ -245,3 +268,20 @@ def test_rounds_bit_identical_with_sink_on():
                     jax.tree.leaves(rounds(reg))):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     assert reg["span.sched.price"].count == 2
+
+
+def test_cross_attention_scope_changes_no_output(monkeypatch):
+    """The tiny encoder-decoder round with the ``cross_attention`` scope and
+    without it: bit-identical outputs."""
+    import repro.models.encdec as encdec
+
+    fn, args = _tiny_round("seamless-m4t-medium", global_sync=False)
+    scoped = fn(*args)
+    real = encdec.scope
+    monkeypatch.setattr(encdec, "scope", lambda name: (
+        contextlib.nullcontext() if name == "cross_attention"
+        else real(name)))
+    fn2, args2 = _tiny_round("seamless-m4t-medium", global_sync=False)
+    assert not any("cross_attention" in s for s in _op_names(fn2, args2))
+    for a, b in zip(jax.tree.leaves(scoped), jax.tree.leaves(fn2(*args2))):
+        assert np.array_equal(np.asarray(a), np.asarray(b))

@@ -68,6 +68,35 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, dtype):
                    ((1, 8, 4096, 128), dtype), ((1, 8, 4096, 128), dtype))
 
 
+@pytest.mark.parametrize("sq,sk,causal,q_dtype,kv_dtype", [
+    (1024, 1024, False, jnp.float32, jnp.float32),     # encoder self
+    (512, 512, True, jnp.bfloat16, jnp.bfloat16),      # decoder self
+    (512, 1024, False, jnp.bfloat16, jnp.float32),     # cross
+])
+def test_flash_attention_training_compiles(one_chip, no_compile_cache,
+                                           monkeypatch, sq, sk, causal,
+                                           q_dtype, kv_dtype):
+    """SeamlessM4T-medium's training attention (16 heads of 64, 2 clients
+    vmapped, the dtypes its round feeds): the kernel forward and the
+    blocked backward compile together, and the kernel is in the program.
+    The platform here is the CPU, so the test makes the kernel compile
+    rather than interpret."""
+    from repro.kernels.flash_attention import kernel, ops
+    monkeypatch.setattr(kernel, "interpret_mode", lambda interpret=None:
+                        False)
+    jax.clear_caches()
+
+    def grads(q, k, v):
+        return jax.vmap(jax.grad(lambda q, k, v: ops.flash_attention(
+            q, k, v, causal, 0, 0.0).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    _assert_mosaic(one_chip, grads, ((2, 1, sq, 16, 64), q_dtype),
+                   ((2, 1, sk, 16, 64), kv_dtype),
+                   ((2, 1, sk, 16, 64), kv_dtype))
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_quantize_compiles(one_chip, no_compile_cache, dtype):
     fn = lambda x, u, s: quantize_dequantize_pallas(x, u, s, qmax=127,
