@@ -42,7 +42,7 @@ from repro.core.split import (GLOBAL_TRAIN, HSFL_TRAIN, split_spec_for,
 from repro.models.registry import Model
 from repro.optim import apply_updates, make_optimizer, masked
 from repro.sharding.rules import data_axes, params_specs
-from repro.telemetry.spans import scope
+from repro.telemetry.spans import get_span_sink, scope
 
 
 # --------------------------------------------------------------- common ----
@@ -69,9 +69,36 @@ def abstract_params(model: Model, *, stacked_clients: int | None = None):
     return shapes
 
 
-def _local_scan(model: Model, tcfg: TrainConfig, opt):
+def embed_update_path(tcfg: TrainConfig, mask) -> str:
+    """How a local step updates the token-embedding table: ``"rows"`` or
+    ``"dense"``.
+
+    ``"rows"`` (see :func:`_local_scan`) is taken where it is exact: the
+    optimizer is SGD (elementwise, no per-leaf state: momentum and AdamW
+    keep table-shaped state) and the mask trains the table.  Everything
+    else updates the whole table, as every leaf is updated."""
+    trains_table = bool(mask.get("embed", {}).get("table", False))
+    return "rows" if tcfg.optimizer == "sgd" and trains_table else "dense"
+
+
+def _with_table(params, table):
+    return {**params, "embed": {**params["embed"], "table": table}}
+
+
+def _local_scan(model: Model, tcfg: TrainConfig, opt, embed_update: str):
     """One client's kappa0 local SGD steps — the SINGLE definition shared by
-    the mesh and host rounds, so their numerics cannot drift apart."""
+    the mesh and host rounds, so their numerics cannot drift apart.
+
+    With ``embed_update="rows"`` and a batch that carries ``tokens``, the
+    steps run on the rows of the embedding table that the round's tokens
+    touch, not on the table: ``rows = table[u]`` for the sorted unique ids
+    ``u``, tokens renumbered so that ``rows[inv] == table[tokens]``, and the
+    rows written back after the last step.  A row a step does not touch
+    has a zero gradient there, as every other row of the table does, so
+    SGD leaves it bit-identical: it is the same SGD step, only repeated
+    ids' cotangents may sum in another order.  No table-sized gradient,
+    zero table or update is made, and the table is not carried through
+    the steps."""
     def scan(p, s, batch_c):
         def local_step(carry, mb):
             pp, ss = carry
@@ -84,7 +111,27 @@ def _local_scan(model: Model, tcfg: TrainConfig, opt):
                 pp = apply_updates(pp, upd)
             return (pp, ss), loss
 
+        rows = embed_update == "rows" and "tokens" in batch_c
+        if rows:
+            table, tokens = p["embed"]["table"], batch_c["tokens"]
+            vocab = table.shape[0]
+            sink = get_span_sink()
+            if sink is not None:
+                sink.gauge("optimizer.embed_row_share").set(
+                    tokens[0].size / vocab)
+            with scope("embed"):
+                # ids padded with the out-of-range `vocab`: never read by
+                # `inv`, dropped (not clamped onto a real row) by the
+                # write-back
+                u, inv = jnp.unique(tokens.ravel(), size=tokens.size,
+                                    fill_value=vocab, return_inverse=True)
+                p = _with_table(p, jnp.take(table, u, axis=0, mode="clip"))
+            batch_c = {**batch_c, "tokens": inv.reshape(tokens.shape)}
         (p, s), losses = jax.lax.scan(local_step, (p, s), batch_c)
+        if rows:
+            with scope("optimizer"):
+                p = _with_table(p, table.at[u].set(p["embed"]["table"],
+                                                   mode="drop"))
         return p, s, losses
 
     return scan
@@ -114,6 +161,7 @@ class PHSFLRound:
                             #  [, mask]) -> (params, opt_state, metrics)
     params_spec: Any        # PartitionSpec tree for the stacked params
     num_clients: int
+    embed_update: str       # embed_update_path: "rows" | "dense"
 
 
 def make_phsfl_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
@@ -133,14 +181,15 @@ def make_phsfl_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
     split in sync with the wireless cut controller's byte accounting.
     """
     cfg = model.cfg
-    opt, _ = build_optimizer(model, tcfg, cut)
+    opt, mask = build_optimizer(model, tcfg, cut)
+    embed_update = embed_update_path(tcfg, mask)
     ca = _client_axes(mesh)
     manual = set(data_axes(mesh))
     num_clients = 1
     for a in data_axes(mesh):
         num_clients *= mesh.shape[a]
 
-    local_scan = _local_scan(model, tcfg, opt)
+    local_scan = _local_scan(model, tcfg, opt, embed_update)
 
     def per_client(params, opt_state, batch_c, au, ab, mask):
         p = _squeeze0(params)
@@ -201,7 +250,8 @@ def make_phsfl_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
     pspec = params_specs(abstract_params(model), model.axes(), mesh, mode="tp")
     pspec = jax.tree.map(lambda s: P(ca, *tuple(s)), pspec,
                         is_leaf=lambda x: isinstance(x, P))
-    return PHSFLRound(fn=round_fn, params_spec=pspec, num_clients=num_clients)
+    return PHSFLRound(fn=round_fn, params_spec=pspec, num_clients=num_clients,
+                      embed_update=embed_update)
 
 
 # --------------------------------------------- host mirror (single device) --
@@ -220,13 +270,14 @@ def make_host_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
     ``cut`` declares the split boundary exactly as in make_phsfl_round
     (a Remark-2 no-op on numerics).
     """
-    opt, _ = build_optimizer(model, tcfg, cut)
+    opt, mask = build_optimizer(model, tcfg, cut)
+    embed_update = embed_update_path(tcfg, mask)
     B = hcfg.num_edge_servers
     assert num_clients % B == 0, (num_clients, B)
     Ub = num_clients // B
     agg_dtype = jnp.dtype(tcfg.agg_dtype)
 
-    local_scan = _local_scan(model, tcfg, opt)
+    local_scan = _local_scan(model, tcfg, opt, embed_update)
 
     def one_client(p, s, bc):
         p, s, losses = local_scan(p, s, bc)
@@ -294,7 +345,8 @@ def make_host_round(model: Model, hcfg: HierarchyConfig, tcfg: TrainConfig,
     else:
         round_fn = lambda pr, st, b, au, ab: round_body(pr, st, b, au, ab,
                                                         None)
-    return PHSFLRound(fn=round_fn, params_spec=None, num_clients=num_clients)
+    return PHSFLRound(fn=round_fn, params_spec=None, num_clients=num_clients,
+                      embed_update=embed_update)
 
 
 def init_stacked_params(model: Model, key, num_clients: int):

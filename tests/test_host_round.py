@@ -197,3 +197,132 @@ def test_mesh_and_host_rounds_agree_under_partial_mask():
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["param_err"] < 5e-3, rec
     assert abs(rec["loss_mesh"] - rec["loss_host"]) < 1e-5, rec
+
+
+# ------------------- row-sparse embedding update of the local step ---------
+def _row_setup(arch, dtype, optimizer="sgd", freeze_table=False):
+    """A reduced model, one client's params, optimizer state and K local
+    micro-batches whose tokens repeat no id within a step."""
+    import dataclasses
+
+    from repro.optim import make_optimizer, masked
+
+    cfg = dataclasses.replace(get_arch(arch).reduced(), dtype=dtype)
+    model = build_model(cfg)
+    tcfg = TrainConfig(learning_rate=0.05, optimizer=optimizer,
+                       freeze_head=True, remat=False)
+    opt, mask = build_optimizer(model, tcfg)
+    if freeze_table:
+        mask = dict(mask, embed={"table": False})
+        opt = masked(make_optimizer(optimizer, tcfg.learning_rate), mask)
+    p = model.init(jax.random.PRNGKey(0))
+    tokens = jnp.stack([
+        jax.random.permutation(jax.random.PRNGKey(k), cfg.vocab_size)
+        [:MICRO * SEQ].reshape(MICRO, SEQ) for k in range(K)])
+    b = {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=-1)}
+    if cfg.encdec is not None:
+        b["source_embeds"] = 0.02 * jax.random.normal(
+            jax.random.PRNGKey(9),
+            (K, MICRO, cfg.encdec.max_source_len, cfg.d_model))
+    return model, tcfg, opt, mask, p, opt.init(p), b
+
+
+def _scan(model, tcfg, opt, path, p, s, b):
+    from repro.core.phsfl import _local_scan
+    return jax.jit(_local_scan(model, tcfg, opt, path))(p, s, b)
+
+
+def _assert_bit_identical(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        assert x.dtype == y.dtype and jnp.array_equal(x, y)
+
+
+ROW_ARCHS = ["xlstm-350m", "seamless-m4t-medium"]
+
+
+@pytest.mark.parametrize("arch", ROW_ARCHS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_embed_rows_bit_identical_to_dense_without_repeats(arch, dtype):
+    """No step repeats an id: each touched row's gradient is one cotangent
+    and gets the same SGD formula, so the two paths agree bit for bit;
+    rows no step touches come back as they went in; the head is frozen."""
+    model, tcfg, opt, _, p, s, b = _row_setup(arch, dtype)
+    dense = _scan(model, tcfg, opt, "dense", p, s, b)
+    rows = _scan(model, tcfg, opt, "rows", p, s, b)
+    _assert_bit_identical(dense, rows)
+    table, new = p["embed"]["table"], rows[0]["embed"]["table"]
+    untouched = np.setdiff1d(np.arange(table.shape[0]),
+                             np.asarray(b["tokens"]).ravel())
+    assert untouched.size >= table.shape[0] - K * MICRO * SEQ
+    assert jnp.array_equal(table[untouched], new[untouched])
+    touched = np.unique(np.asarray(b["tokens"]))
+    assert not jnp.array_equal(table[touched], new[touched])
+    _assert_bit_identical(p["lm_head"], rows[0]["lm_head"])
+
+
+@pytest.mark.parametrize("arch", ROW_ARCHS)
+def test_embed_rows_with_repeated_ids_match_dense(arch):
+    """Repeated ids sum their cotangents in the same dtype, possibly in
+    another order: within the round's parity tolerance at f32.  The last
+    row is touched too, so a padding id clamped onto it (not dropped)
+    would overwrite its update."""
+    model, tcfg, opt, _, p, s, b = _row_setup(arch, "float32")
+    last = p["embed"]["table"].shape[0] - 1
+    tokens = jax.random.randint(jax.random.PRNGKey(3), b["tokens"].shape,
+                                0, 20).at[:, :, 0].set(last)
+    b = dict(b, tokens=tokens, labels=jnp.roll(tokens, -1, axis=-1))
+    dense = _scan(model, tcfg, opt, "dense", p, s, b)
+    rows = _scan(model, tcfg, opt, "rows", p, s, b)
+    for x, y in zip(jax.tree.leaves(dense), jax.tree.leaves(rows),
+                    strict=True):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=2e-5, atol=2e-6)
+    table, new = p["embed"]["table"], rows[0]["embed"]["table"]
+    assert jnp.array_equal(table[20:last], new[20:last])
+    assert not jnp.array_equal(table[last], new[last])
+    _assert_bit_identical(p["lm_head"], rows[0]["lm_head"])
+
+
+@pytest.mark.parametrize("optimizer,freeze_table", [
+    ("momentum", False), ("adamw", False), ("sgd", True)])
+def test_embed_dense_path_where_rows_are_not_exact(optimizer, freeze_table):
+    """Momentum and AdamW keep table-shaped state, and a frozen table has
+    nothing to update: those take the dense path, unchanged."""
+    from repro.core.phsfl import embed_update_path
+
+    model, tcfg, opt, mask, p, s, b = _row_setup(
+        "xlstm-350m", "float32", optimizer, freeze_table)
+    path = embed_update_path(tcfg, mask)
+    assert path == "dense"
+    _assert_bit_identical(_scan(model, tcfg, opt, path, p, s, b),
+                          _scan(model, tcfg, opt, "dense", p, s, b))
+
+
+@pytest.mark.parametrize("optimizer,path", [
+    ("sgd", "rows"), ("momentum", "dense"), ("adamw", "dense")])
+def test_round_reports_embed_update_path(optimizer, path):
+    cfg = get_arch("seamless-m4t-medium").reduced()
+    model = build_model(cfg)
+    hcfg = HierarchyConfig(num_edge_servers=1, clients_per_es=C, kappa0=K,
+                           kappa1=1)
+    tcfg = TrainConfig(learning_rate=0.05, optimizer=optimizer,
+                       freeze_head=True, remat=False)
+    rnd = make_host_round(model, hcfg, tcfg, num_clients=C,
+                          global_sync=False, participation=True)
+    assert rnd.embed_update == path
+
+
+def test_embed_rows_share_recorded_with_a_span_sink():
+    """With a span sink installed, tracing the row path records the share
+    of table rows a step may write."""
+    from repro.telemetry import MetricsRegistry, set_span_sink
+
+    model, tcfg, opt, _, p, s, b = _row_setup("xlstm-350m", "float32")
+    reg = MetricsRegistry()
+    set_span_sink(reg)
+    try:
+        _scan(model, tcfg, opt, "rows", p, s, b)
+    finally:
+        set_span_sink(None)
+    share = reg.gauge("optimizer.embed_row_share").value
+    assert share == MICRO * SEQ / model.cfg.padded_vocab

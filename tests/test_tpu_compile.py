@@ -121,3 +121,67 @@ def test_rglru_scan_compiles(one_chip, no_compile_cache, dtype):
     fn = lambda a, b, h: rglru_scan_pallas(a, b, h, interpret=False)
     _assert_mosaic(one_chip, fn, ((1, 4096, 2560), dtype),
                    ((1, 4096, 2560), dtype), ((1, 2560), jnp.float32))
+
+
+def test_round_optimizer_writes_no_whole_embedding_table(one_chip,
+                                                         no_compile_cache):
+    """The edge round's local SGD step updates only the embedding rows its
+    tokens touch: compiled for a v5e, no instruction under the
+    ``optimizer`` scope is shaped like the clients' tables, (C, V, D) or
+    (C*V, D), apart from free bitcasts and the scatter that writes the
+    updated rows back in place, and no table-sized zero gradient is
+    broadcast anywhere.  A reduced-width encoder-decoder round with a 32k
+    vocabulary, 2 clients x 2 local steps."""
+    import dataclasses
+    import re
+
+    from repro.configs.base import HierarchyConfig, TrainConfig
+    from repro.configs.registry import get_arch
+    from repro.core import build_optimizer, make_host_round
+    from repro.models import build_model
+
+    C, K, S = 2, 2, 64
+    cfg = dataclasses.replace(
+        get_arch("seamless-m4t-medium").reduced(vocab_size=32768),
+        dtype="bfloat16")
+    model = build_model(cfg)
+    hcfg = HierarchyConfig(num_edge_servers=1, clients_per_es=C, kappa0=K,
+                           kappa1=1)
+    tcfg = TrainConfig(learning_rate=0.05, freeze_head=True, remat=False)
+    opt, _ = build_optimizer(model, tcfg)
+    rnd = make_host_round(model, hcfg, tcfg, num_clients=C,
+                          global_sync=False, participation=True)
+    assert rnd.embed_update == "rows"
+
+    def stacked(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            (C,) + s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((C, K, 1, S), jnp.int32, sharding=one_chip)
+    src = jax.ShapeDtypeStruct(
+        (C, K, 1, cfg.encdec.max_source_len, cfg.d_model), jnp.float32,
+        sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((C,), jnp.float32, sharding=one_chip)
+    hlo = jax.jit(rnd.fn).lower(
+        stacked(params), stacked(jax.eval_shape(opt.init, params)),
+        {"tokens": toks, "labels": toks, "source_embeds": src},
+        vec, vec, vec).compile().as_text()
+
+    V, D = cfg.padded_vocab, cfg.d_model
+    table = re.compile(r"= \w+\[(%d,%d,%d|%d,%d)\]\S* (\S+?)\(" % (
+        C, V, D, C * V, D))
+    in_optimizer, broadcasts = [], []
+    for line in hlo.splitlines():
+        m = table.search(line)
+        if m is None:
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        if ("/optimizer/" in op_name and m.group(2) != "bitcast"
+                and not op_name.endswith("/scatter")):
+            in_optimizer.append(line.strip())
+        if m.group(2) == "broadcast" and "/edge_agg/" not in op_name:
+            broadcasts.append(line.strip())
+    assert not in_optimizer, in_optimizer[:3]
+    assert not broadcasts, broadcasts[:3]
